@@ -18,6 +18,7 @@ from repro.core import latency
 from repro.core.allocator import edge_tpu_compiler_plan, swapless_plan
 from repro.core.planner import TenantSpec
 from repro.hw.specs import EDGE_TPU_PLATFORM
+from repro.launch.serve import report_execution
 from repro.models.cnn import PAPER_CNN_SPECS, build_executable
 from repro.serving.engine import ServingEngine
 from repro.serving.simulator import simulate
@@ -56,13 +57,13 @@ def main() -> None:
             for s in range(n_req):
                 eng.submit(i, m.make_input(s))
         done = eng.drain(timeout=180.0)
-        print(f"real engine: {len(done)}/{len(NAMES)*n_req} requests completed")
-        for i, n in enumerate(NAMES):
-            outs = [c for c in done if c.model_idx == i]
-            ok = all(np.isfinite(np.asarray(c.output)).all() for c in outs)
-            print(f"  {n:<14} n={len(outs)} outputs_finite={ok}")
     finally:
         eng.shutdown()
+    report_execution(done, NAMES)  # exits nonzero on any errored record
+    for i, n in enumerate(NAMES):
+        outs = [c.output for c in done if c.model_idx == i]
+        ok = all(np.isfinite(np.asarray(o)).all() for o in outs)
+        print(f"  {n:<14} outputs_finite={ok}")
 
 
 if __name__ == "__main__":

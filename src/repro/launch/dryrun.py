@@ -1,4 +1,6 @@
 import os
+# The dry run compiles on forced host devices, never on an attached chip.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape) on the
@@ -22,7 +24,7 @@ import traceback
 import jax
 
 from repro.configs import ARCHS, INPUT_SHAPES
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.launch.steps import build_step
 from repro.roofline.analysis import analyze_compiled
 
@@ -84,14 +86,14 @@ def run_one(
         mesh = make_production_mesh(multi_pod=multi_pod)
     else:
         if multi_pod:
-            mesh = jax.make_mesh((2, *mesh_shape), ("pod", "data", "model"))
+            mesh = auto_mesh((2, *mesh_shape), ("pod", "data", "model"))
         else:
-            mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+            mesh = auto_mesh(mesh_shape, ("data", "model"))
         record["mesh_factorization"] = list(mesh_shape)
     bundle = build_step(cfg, shape, mesh)
     t0 = time.time()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 bundle.fn,
                 in_shardings=bundle.in_shardings,

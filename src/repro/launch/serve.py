@@ -14,6 +14,7 @@ edge testbed.
 from __future__ import annotations
 
 import argparse
+from typing import Sequence
 
 import numpy as np
 
@@ -25,10 +26,34 @@ from repro.core.allocator import (
 )
 from repro.core.planner import TenantSpec
 from repro.hw.specs import EDGE_TPU_PLATFORM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import PAPER_CNN_SPECS, build_executable
-from repro.serving.engine import ServingEngine
+from repro.serving.engine import CompletedRequest, ServingEngine
 from repro.serving.simulator import simulate
 from repro.serving.workload import poisson_trace
+
+
+def report_execution(
+    done: Sequence[CompletedRequest], names: Sequence[str]
+) -> None:
+    """Print per-model latency over the successful records and exit nonzero
+    when any record errored; an errored record never enters a mean."""
+    ok = [c for c in done if c.ok]
+    failed = [c for c in done if not c.ok]
+    print(f"real execution: {len(ok)}/{len(done)} requests ok")
+    for i, name in enumerate(names):
+        ls = np.array([c.latency for c in ok if c.model_idx == i])
+        if ls.size:
+            print(
+                f"  {name:<14} n={ls.size} mean={ls.mean()*1e3:.2f}ms "
+                f"p95={np.percentile(ls, 95)*1e3:.2f}ms"
+            )
+        else:
+            print(f"  {name:<14} n=0")
+    for c in failed:
+        print(f"  {names[c.model_idx]:<14} error: {c.error!r}")
+    if failed:
+        raise SystemExit(f"{len(failed)} of {len(done)} requests failed")
 
 
 def main() -> None:
@@ -40,6 +65,7 @@ def main() -> None:
                     help="real-execution requests per model")
     ap.add_argument("--k-max", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = args.models.split(",")
     rates = [float(r) for r in args.rates.split(",")]
@@ -82,18 +108,9 @@ def main() -> None:
             for s in range(args.requests):
                 eng.submit(i, m.make_input(s))
         done = eng.drain(timeout=120.0)
-        by_model: dict[int, list[float]] = {}
-        for c in done:
-            by_model.setdefault(c.model_idx, []).append(c.latency)
-        print(f"real execution: {len(done)} requests completed")
-        for i, name in enumerate(names):
-            ls = np.array(by_model.get(i, [0.0]))
-            print(
-                f"  {name:<14} n={len(ls)} mean={ls.mean()*1e3:.2f}ms "
-                f"p95={np.percentile(ls, 95)*1e3:.2f}ms"
-            )
     finally:
         eng.shutdown()
+    report_execution(done, names)
 
 
 if __name__ == "__main__":

@@ -17,21 +17,9 @@ from jax.sharding import PartitionSpec as P
 
 
 def _active_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh set by an enclosing ``with jax.set_mesh(mesh):``, else None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x: jax.Array, *spec_tokens) -> jax.Array:

@@ -175,7 +175,12 @@ def _tpu_replicas_kernel(
     """
     svc = base * scales[:, tm] + miss_load  # [R, P]
     d = _delays_kernel(svc, svc - g, x_init, c, l)
-    sums = d @ jax.nn.one_hot(tm, n_models, dtype=d.dtype)
+    # HIGHEST: a DEFAULT float32 dot runs as one bf16 pass on a TPU, far
+    # outside the ~1e-4 relative contract on the per-model means.
+    sums = jnp.dot(
+        d, jax.nn.one_hot(tm, n_models, dtype=d.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     busy = svc.sum(axis=1)
     return d, sums, busy
 

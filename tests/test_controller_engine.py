@@ -408,22 +408,22 @@ class TestAdaptiveDesBackend:
 
 
 def _make_mlp_model(name: str, n_segments: int, dim: int, seed: int) -> ExecutableModel:
-    key = jax.random.PRNGKey(seed)
-    weights = []
-    for i in range(n_segments):
-        key, sub = jax.random.split(key)
-        weights.append(jax.random.normal(sub, (dim, dim), jnp.float32) / jnp.sqrt(dim))
+    rng = np.random.default_rng(seed)
+    weights = tuple(
+        rng.standard_normal((dim, dim), dtype=np.float32) / np.float32(np.sqrt(dim))
+        for _ in range(n_segments)
+    )
 
-    def make_seg(w):
-        @jax.jit
-        def seg(x):
-            return jnp.tanh(x @ w)
-        return seg
+    def seg(w, x):
+        return jnp.tanh(x @ w)
 
     return ExecutableModel(
         name=name,
-        segments=tuple(make_seg(w) for w in weights),
-        make_input=lambda s: jax.random.normal(jax.random.PRNGKey(s), (1, dim)),
+        segments=(seg,) * n_segments,
+        params=weights,
+        make_input=lambda s: np.random.default_rng(s).standard_normal(
+            (1, dim), dtype=np.float32
+        ),
     )
 
 
@@ -450,8 +450,8 @@ class TestServingEngine:
                 expect = set()
                 for s in range(5):
                     x = m.make_input(s)
-                    for seg in m.segments:
-                        x = seg(x)
+                    for seg, w in zip(eng._segments[i], m.params):
+                        x = seg(w, x)
                     expect.add(np.asarray(x).tobytes())
                 assert outs == expect
         finally:
@@ -492,24 +492,28 @@ class TestServingEngine:
         # not a dead worker thread holding the in-flight count forever.
         base = _make_mlp_model("a", 2, 16, 0)
 
-        def raise_on_nan(x):
-            if bool(np.isnan(np.asarray(x)).any()):
+        def raise_on_poison(_, x):
+            # The engine jits every segment, so the poison is a batch size
+            # this segment refuses while tracing: its RuntimeError reaches
+            # the record as raised.
+            if x.shape[0] != 1:
                 raise RuntimeError("poisoned input")
             return x
 
         model = ExecutableModel(
             name="poison",
-            segments=(base.segments[0], raise_on_nan, base.segments[1]),
+            segments=(base.segments[0], raise_on_poison, base.segments[1]),
+            params=(base.params[0], None, base.params[1]),
             make_input=base.make_input,
         )
         # (partition, cores): all-prefix exercises the TPU-worker except
-        # path; split exercises the CPU suffix-pool except path (NaN rides
-        # through the jitted first segment into the raising one).
+        # path; split exercises the CPU suffix-pool except path (the poison
+        # rides through the first segment into the raising one).
         for part, cores in ((3, 0), (1, 1)):
             eng = ServingEngine([model], Plan((part,), (cores,)), k_max=4)
             try:
                 good = model.make_input(0)
-                bad = jnp.full((1, 16), jnp.nan)
+                bad = jnp.ones((2, 16))
                 eng.submit(0, good)
                 eng.submit(0, bad)
                 eng.submit(0, good)
@@ -518,6 +522,7 @@ class TestServingEngine:
                 errs = [c for c in done if not c.ok]
                 assert len(errs) == 1
                 assert isinstance(errs[0].error, RuntimeError)
+                assert "poisoned input" in str(errs[0].error)
                 assert errs[0].output is None
                 assert all(c.error is None for c in done if c.ok)
                 # The engine keeps serving after the failure.
@@ -545,3 +550,17 @@ class TestServingEngine:
             assert len(done) == 1 and done[0].ok
         finally:
             eng.shutdown()
+
+    def test_serve_exit_path_fails_on_errored_record(self, capsys):
+        from repro.launch.serve import report_execution
+        from repro.serving.engine import CompletedRequest
+
+        good = CompletedRequest(0, 0.0, 0.5, np.zeros(1))
+        bad = CompletedRequest(1, 0.0, 9.0, None, error=RuntimeError("boom"))
+        report_execution([good], ["a", "b"])  # all ok: returns normally
+        with pytest.raises(SystemExit) as exc:
+            report_execution([good, bad], ["a", "b"])
+        assert exc.value.code not in (0, None)
+        out = capsys.readouterr().out
+        # The errored record's 9 s never enters b's latency mean.
+        assert "b              n=0" in out and "boom" in out

@@ -94,8 +94,24 @@ class TestRealShardedExecution:
         params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
         batch = make_train_batch(cfg, 2, 32)
         mesh = make_host_mesh(1, 1)
-        with mesh:
+        with jax.set_mesh(mesh):
             loss, _ = jax.jit(
                 lambda p, b: forward_loss(cfg, p, b, remat=False)
             )(params, batch)
         assert np.isfinite(float(loss))
+
+    @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "grok-1-314b"])
+    def test_constraints_reach_lowered_program(self, name):
+        """Under a set mesh ``constrain`` must emit sharding constraints;
+        a ``constrain`` that silently returns its input fails here."""
+        from repro.models.frontend import make_train_batch
+        from repro.models.transformer import forward_loss
+
+        cfg = ARCHS[name].reduced()
+        params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        batch = make_train_batch(cfg, 2, 32)
+        fn = jax.jit(lambda p, b: forward_loss(cfg, p, b, remat=False))
+        assert "sharding_constraint" not in fn.lower(params, batch).as_text()
+        with jax.set_mesh(make_host_mesh(1, 1)):
+            text = fn.lower(params, batch).as_text()
+        assert "sharding_constraint" in text
