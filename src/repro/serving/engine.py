@@ -12,10 +12,33 @@ params are committed to the device that runs it under the current plan;
 CPU-only process both devices are the CPU.  Latency *validation* against
 the paper's edge testbed is done by the discrete-event simulator, which
 models that platform's timing.
+
+Tracing.  Every completion record carries its request's phase stamps on
+the host clock (``time.perf_counter``, as ``submit_time``), always on.
+The engine also writes ``jax.profiler.TraceAnnotation`` spans, which cost
+about a microsecond each while no profiler runs and land on the device
+trace's clock while one does.  Each request span carries ``req`` (the
+record's ``req_id``) and ``tenant`` as arguments:
+
+- ``engine.worker_wait``: from submit until the accelerator worker takes
+  the request from its inbox, and ``engine.pool_wait``: from the handoff
+  until a host pool thread starts the suffix.  Each is opened by one
+  thread and closed by the next, so the trace puts it on the closing
+  thread.
+- ``engine.prefix`` on the worker, with children ``engine.h2d`` (the
+  input's ``device_put``), ``engine.launch`` (the stage dispatch loop)
+  and ``engine.sync`` (``block_until_ready``).
+- ``engine.suffix`` on a pool thread, with children ``engine.cut`` (the
+  ``device_put`` to the host), ``engine.launch`` and ``engine.sync``.
+
+Each stage program is jitted as ``<model>_stage<s>``, so the device's
+modules in a trace say which tenant and stage they run.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import math
 import queue
 import threading
 import time
@@ -23,6 +46,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Sequence
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.planner import Plan
 
@@ -60,6 +84,19 @@ class CompletedRequest:
     # The device that held the prefix's output activation; None when the
     # plan ran no prefix (partition 0) or the prefix failed.
     prefix_device: jax.Device | None = None
+    # Unique, increasing in submit order; the ``req`` of the request's spans.
+    req_id: int = -1
+    # Phase stamps, on the clock of ``submit_time``: the worker takes the
+    # request from its inbox; its prefix is ready; a host pool thread starts
+    # the suffix.  ``nan`` where the phase does not exist (no prefix at
+    # partition 0, no suffix at partition P) or was not reached before an
+    # error.  The phases between consecutive stamps add up to ``latency``.
+    prefix_start: float = math.nan
+    prefix_end: float = math.nan
+    suffix_start: float = math.nan
+    # Bytes of the activation handed from the prefix to the suffix; 0 where
+    # the plan has no cut.
+    cut_bytes: int = 0
 
     @property
     def latency(self) -> float:
@@ -80,6 +117,29 @@ def _host_device() -> jax.Device:
         ) from exc
 
 
+def _stage_program(fn: SegmentFn, name: str):
+    """``fn`` jitted as a program named ``name`` (``jit_<name>`` on the
+    device), whatever ``fn`` is: a closure, a ``functools.partial``."""
+
+    def stage(params: Any, x: Any) -> Any:
+        return fn(params, x)
+
+    stage.__name__ = stage.__qualname__ = name
+    return jax.jit(stage)
+
+
+def _span_args(rec: CompletedRequest) -> dict:
+    return {"req": rec.req_id, "tenant": rec.model_idx}
+
+
+def _open_span(name: str, rec: CompletedRequest) -> TraceAnnotation:
+    """A request's span entered here and left, with ``__exit__``, by the
+    thread that takes the request next: its wait in a queue."""
+    span = TraceAnnotation(name, **_span_args(rec))
+    span.__enter__()
+    return span
+
+
 class _TpuWorker(threading.Thread):
     """Single global FCFS worker executing prefixes on the accelerator."""
 
@@ -93,7 +153,10 @@ class _TpuWorker(threading.Thread):
             item = self.inbox.get()
             if item is None:
                 return
-            self.engine._run_prefix(*item)
+            rec, x, p, params, wait = item
+            rec.prefix_start = time.perf_counter()
+            wait.__exit__(None, None, None)
+            self.engine._run_prefix(rec, x, p, params)
 
 
 class ServingEngine:
@@ -113,7 +176,13 @@ class ServingEngine:
         self.k_max = k_max
         self.accel_device = jax.devices()[0]
         self.host_device = _host_device()
-        self._segments = [tuple(jax.jit(f) for f in m.segments) for m in self.models]
+        self._segments = [
+            tuple(
+                _stage_program(f, f"{m.name}_stage{s}")
+                for s, f in enumerate(m.segments)
+            )
+            for m in self.models
+        ]
         # Per model: each segment's params committed to the device that runs
         # it under ``self.plan``.  Requests snapshot the tuple at submit, so
         # a plan switch never pulls params from under an in-flight request.
@@ -124,6 +193,7 @@ class ServingEngine:
         self._completed: "queue.Queue[CompletedRequest]" = queue.Queue()
         self._inflight = 0
         self._inflight_lock = threading.Lock()
+        self._req_ids = itertools.count()
         self._drained = threading.Event()
         self._drained.set()
         self.set_plan(plan)
@@ -166,91 +236,99 @@ class ServingEngine:
         with self._inflight_lock:
             self._inflight += 1
             self._drained.clear()
-        submit_t = time.perf_counter()
+        rec = CompletedRequest(
+            model_idx, time.perf_counter(), math.nan, None, req_id=next(self._req_ids)
+        )
         with self._plan_lock:
             p = self.plan.partition[model_idx]
             params = self._placed[model_idx]
         if p > 0:
-            self._tpu.inbox.put((model_idx, x, p, params, submit_t))
+            wait = _open_span("engine.worker_wait", rec)
+            self._tpu.inbox.put((rec, x, p, params, wait))
         else:
             try:
-                self._dispatch_suffix(model_idx, x, 0, params, submit_t, None)
+                self._dispatch_suffix(rec, x, 0, params)
             except BaseException as exc:
                 # The synchronous dispatch path (zero-core misconfiguration,
                 # pool rejection) must not leak the in-flight slot it just
                 # claimed: record the failure so drain() terminates, then
                 # surface it to the submitter.
-                self._finish(model_idx, None, submit_t, error=exc)
+                self._finish(rec, None, error=exc)
                 raise
 
     def _run_prefix(
-        self, model_idx: int, x: Any, p: int, params: tuple, submit_t: float
+        self, rec: CompletedRequest, x: Any, p: int, params: tuple
     ) -> None:
         # Any failure here (a segment raising, a missing suffix pool) would
         # otherwise die inside the TPU worker thread with the in-flight count
         # still held, hanging every future drain().
-        try:
-            segs = self._segments[model_idx]
-            x = jax.device_put(x, self.accel_device)
-            for seg, w in zip(segs[:p], params[:p]):
-                x = seg(w, x)
-            x = jax.block_until_ready(x)
-            if p < len(segs):
-                self._dispatch_suffix(model_idx, x, p, params, submit_t, x.device)
-            else:
-                self._finish(model_idx, x, submit_t, prefix_device=x.device)
-        except BaseException as exc:
-            self._finish(model_idx, None, submit_t, error=exc)
+        args = _span_args(rec)
+        with TraceAnnotation("engine.prefix", **args):
+            try:
+                segs = self._segments[rec.model_idx]
+                with TraceAnnotation("engine.h2d", **args):
+                    x = jax.device_put(x, self.accel_device)
+                with TraceAnnotation("engine.launch", **args):
+                    for seg, w in zip(segs[:p], params[:p]):
+                        x = seg(w, x)
+                with TraceAnnotation("engine.sync", **args):
+                    x = jax.block_until_ready(x)
+                rec.prefix_end = time.perf_counter()
+                rec.prefix_device = x.device
+                if p < len(segs):
+                    self._dispatch_suffix(rec, x, p, params)
+                else:
+                    self._finish(rec, x)
+            except BaseException as exc:
+                self._finish(rec, None, error=exc)
 
     def _dispatch_suffix(
-        self,
-        model_idx: int,
-        x: Any,
-        p: int,
-        params: tuple,
-        submit_t: float,
-        prefix_device: jax.Device | None,
+        self, rec: CompletedRequest, x: Any, p: int, params: tuple
     ) -> None:
-        pool = self._pools[model_idx]
+        pool = self._pools[rec.model_idx]
         if pool is None:
             raise RuntimeError(
-                f"model {model_idx} has a CPU suffix but zero cores allocated"
+                f"model {rec.model_idx} has a CPU suffix but zero cores allocated"
             )
+        if p > 0:
+            rec.cut_bytes = x.nbytes
+        args = _span_args(rec)
+        wait = _open_span("engine.pool_wait", rec)
 
         def work() -> None:
+            rec.suffix_start = time.perf_counter()
+            wait.__exit__(None, None, None)
             # Same containment as _run_prefix: a suffix failure becomes an
             # errored completion record, never a silently swallowed pool
             # exception plus a leaked in-flight slot.
-            try:
-                y = jax.device_put(x, self.host_device)
-                for seg, w in zip(self._segments[model_idx][p:], params[p:]):
-                    y = seg(w, y)
-                y = jax.block_until_ready(y)
-            except BaseException as exc:
-                self._finish(model_idx, None, submit_t, error=exc)
-            else:
-                self._finish(model_idx, y, submit_t, prefix_device=prefix_device)
+            with TraceAnnotation("engine.suffix", **args):
+                try:
+                    with TraceAnnotation("engine.cut", **args):
+                        y = jax.device_put(x, self.host_device)
+                    with TraceAnnotation("engine.launch", **args):
+                        segs = self._segments[rec.model_idx]
+                        for seg, w in zip(segs[p:], params[p:]):
+                            y = seg(w, y)
+                    with TraceAnnotation("engine.sync", **args):
+                        y = jax.block_until_ready(y)
+                except BaseException as exc:
+                    self._finish(rec, None, error=exc)
+                else:
+                    self._finish(rec, y)
 
-        pool.submit(work)
+        try:
+            pool.submit(work)
+        except BaseException:
+            wait.__exit__(None, None, None)
+            raise
 
     def _finish(
-        self,
-        model_idx: int,
-        out: Any,
-        submit_t: float,
-        error: BaseException | None = None,
-        prefix_device: jax.Device | None = None,
+        self, rec: CompletedRequest, out: Any, error: BaseException | None = None
     ) -> None:
-        self._completed.put(
-            CompletedRequest(
-                model_idx=model_idx,
-                submit_time=submit_t,
-                done_time=time.perf_counter(),
-                output=out,
-                error=error,
-                prefix_device=prefix_device,
-            )
-        )
+        rec.done_time = time.perf_counter()
+        rec.output = out
+        rec.error = error
+        self._completed.put(rec)
         with self._inflight_lock:
             self._inflight -= 1
             if self._inflight == 0:
