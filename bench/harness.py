@@ -4,15 +4,33 @@
 (``configs[].file``), a traffic mix (``bench/mixes/<traffic>.json``) and
 its metrics, each read by ``bench/metrics/<metric>.py`` (or, for a metric
 split by a suffix such as ``prefix_roofline.overload``, by the reader of
-the part before the first dot).  Adding a cell, a mix or a metric adds
-files and entries and edits none.
+the part before the first dot).  The configuration's ``family`` key names
+its model family, ``bench/families/<family>.py``; a configuration without
+the key is a conv chain (``conv_chain``).  Adding a cell, a mix, a metric
+or a configuration of a new family adds files and entries and edits none.
 
-A run builds the configuration's tenants with the program's own model code
-and weights drawn from the seed on the device, plans them with the
-program's planner at the mix's offered rates, warms every stage program up
-through ``ServingEngine``, then offers the seeded schedule open loop for
+A family module provides, and the harness knows nothing else of the
+model:
+
+- ``make_data(config, pool_size, seed, device) -> (params, pools)``: the
+  weights and each tenant's inputs, drawn from the seed.  ``pools[m]`` is
+  a sequence that the schedule's ``item`` indexes, each element what
+  ``ServingEngine.submit`` takes; the family decides where weights are
+  drawn and held.
+- ``build_models(config, params, control)``: the tenants as the program
+  builds them, a list of ``ExecutableModel``; under ``control``, one of
+  the module's ``CONTROLS``, the reference at that precision instead.
+- ``make_plan(config, rates)``: the program's ``Plan`` at those rates.
+- ``references(config, host_params, pools)``: ``refs[m][item]``, from a
+  plain reference that imports nothing of the program.
+- ``stage_costs(config, tenant)``: a ``bench.roofline.StageCost`` per
+  stage.
+
+A run makes the data, plans the tenants at the mix's offered rates, warms
+every stage program up through ``ServingEngine`` on one input of each
+shape in a tenant's pool, then offers the seeded schedule open loop for
 the window from this one thread, drains, and checks every output against
-the plain reference (``bench/reference.py``).
+the family's reference.
 """
 from __future__ import annotations
 
@@ -34,6 +52,10 @@ from bench.compile_clock import CompileClock
 
 MIXES_DIR = "bench/mixes"
 METRICS_DIR = "bench/metrics"
+FAMILIES_DIR = "bench/families"
+DEFAULT_FAMILY = "conv_chain"
+# The tree this file belongs to.
+ROOT = Path(__file__).resolve().parents[1]
 # The profiler records this much of the window, from its start: a whole
 # window's trace at these rates is hundreds of MB and takes longer to read
 # than a run may last.
@@ -72,6 +94,14 @@ def reader_path(root: Path, metric: str) -> Path:
     raise FileNotFoundError(f"no reader {root / METRICS_DIR}/{metric}.py")
 
 
+def family_path(root: Path, config: dict) -> Path:
+    """The module of ``config``'s model family."""
+    path = root / FAMILIES_DIR / f"{config.get('family', DEFAULT_FAMILY)}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family module {path}")
+    return path
+
+
 def load_cell(root: Path, workload: str) -> Cell:
     """The cell ``workload`` of ``root/BENCHMARK.json`` and its files."""
     spec = _read_json(root / "BENCHMARK.json")
@@ -87,121 +117,58 @@ def load_cell(root: Path, workload: str) -> Cell:
             for kind in ("end_to_end", "per_layer")}
     for m in mine["end_to_end"] + mine["per_layer"]:
         reader_path(root, m["name"])
-    return Cell(name=workload, chips=int(wl["chips"]),
-                config=_read_json(root / cfg["file"]),
+    config = _read_json(root / cfg["file"])
+    family_path(root, config)
+    return Cell(name=workload, chips=int(wl["chips"]), config=config,
                 mix=_read_json(root / MIXES_DIR / f"{wl['traffic']}.json"),
                 **mine)
 
 
 @functools.lru_cache(maxsize=None)
-def _reader(path: Path):
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{path.stem}", path)
+def _module(path: Path, prefix: str):
+    spec = importlib.util.spec_from_file_location(f"{prefix}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
-# -- set-up --------------------------------------------------------------------
-def stage_shapes(config: dict, tenant: dict) -> list[dict]:
-    k, c_in, out = int(config["kernel"]), int(config["in_channels"]), []
-    for c in tenant["stage_channels"]:
-        out.append({"conv": (k, k, c_in, c), "pw": (1, 1, c, c)})
-        c_in = c
-    return out
+def _reader(path: Path):
+    return _module(path, "bench_metric").read
 
 
+def family(root: Path, config: dict):
+    """The loaded module of ``config``'s model family under ``root``."""
+    return _module(family_path(root, config), "bench_family")
+
+
+# The family's set-up steps for a caller that holds a configuration but no
+# tree, the family being this tree's.  Their one caller is
+# tests/test_engine_tracing.py; they go once it calls ``family`` instead.
 def make_data(config: dict, pool_size: int, seed: int, device):
-    """Every tenant's stage weights and input pool, drawn from ``seed`` on
-    ``device`` in one jitted call, in float32 as they are served.  Weights
-    follow the program's scaling: standard normal over the root of the
-    fan-in.  One draw covers all weights and one each tenant's pool, so
-    the program stays small to trace and compile."""
-    import jax
-    import jax.numpy as jnp
-
-    tenants, c_in = config["tenants"], int(config["in_channels"])
-    leaves = [(m, name, shp[name])
-              for m, t in enumerate(tenants)
-              for shp in stage_shapes(config, t) for name in ("conv", "pw")]
-
-    def gen(lo, hi):
-        key = jax.random.fold_in(jax.random.key(lo), hi)
-        flat = jax.random.normal(jax.random.fold_in(key, 0),
-                                 (sum(math.prod(s) for _, _, s in leaves),))
-        params, off = [[] for _ in tenants], 0
-        for m, name, s in leaves:
-            n = math.prod(s)
-            fan_in = math.prod(s[:3])
-            w = flat[off:off + n].reshape(s) / math.sqrt(fan_in)
-            off += n
-            if name == "conv":
-                params[m].append({"conv": w})
-            else:
-                params[m][-1]["pw"] = w
-        pools = [jax.random.normal(jax.random.fold_in(key, 1 + m),
-                                   (pool_size, 1, int(t["input_size"]),
-                                    int(t["input_size"]), c_in), jnp.float32)
-                 for m, t in enumerate(tenants)]
-        return params, pools
-
-    # Seeds may pass 32 bits: the high part is folded in.
-    with jax.default_device(device):
-        return jax.jit(gen)(np.uint32(seed & 0xFFFFFFFF), np.uint32(seed >> 32))
+    return family(ROOT, config).make_data(config, pool_size, seed, device)
 
 
-def build_models(config: dict, params: list, control: str | None):
-    """The tenants as the program builds them (``build_executable``), with
-    the seed's weights; under ``control`` their stages are the reference's
-    at that precision instead."""
-    import jax
-    from repro.models.cnn import CNNSpec, build_executable
-
-    models = []
-    for t, p in zip(config["tenants"], params):
-        spec = CNNSpec(t["name"], tuple(t["stage_channels"]),
-                       in_size=int(t["input_size"]),
-                       in_channels=int(config["in_channels"]),
-                       kernel=int(config["kernel"]))
-        model = build_executable(spec)
-        want = jax.tree.map(np.shape, list(model.params))
-        got = jax.tree.map(np.shape, p)
-        if want != got:
-            raise ValueError(f"{t['name']}: the program's stage weights are "
-                             f"{want}, the configuration's {got}")
-        segments = model.segments
-        if control is not None:
-            segments = tuple(
-                functools.partial(reference.stage, stride=s, precision=control)
-                for s in reference.strides(config, len(p)))
-        models.append(dataclasses.replace(model, params=tuple(p),
-                                          segments=segments))
-    return models
+def build_models(config: dict, params, control: str | None):
+    return family(ROOT, config).build_models(config, params, control)
 
 
 def make_plan(config: dict, rates):
-    """The program's SwapLess plan at the offered per-tenant rates."""
-    import repro.hw.specs as specs
-    from repro.configs.paper_models import paper_profile
-    from repro.core.allocator import swapless_plan
-    from repro.core.planner import TenantSpec
-
-    platform = getattr(specs, config["planner_platform"])
-    tenants = []
-    for t, r in zip(config["tenants"], rates):
-        prof = paper_profile(t["profile"], platform)
-        if prof.num_partition_points != len(t["stage_channels"]):
-            raise ValueError(f"{t['name']}: profile {t['profile']!r} has "
-                             f"{prof.num_partition_points} partition points, "
-                             f"the tenant {len(t['stage_channels'])} stages")
-        tenants.append(TenantSpec(prof, float(r)))
-    return swapless_plan(tenants, platform, int(config["k_max"]))
+    return family(ROOT, config).make_plan(config, rates)
 
 
+# -- set-up --------------------------------------------------------------------
 def warm_up(engine, pools, timeout: float) -> None:
-    """Every tenant once through the engine: each stage program of the plan
-    compiles (or loads from the cache) on the device that runs it."""
+    """The first input of each shape in every tenant's pool once through the
+    engine: each stage program of the plan compiles (or loads from the
+    cache) for every shape the window will send, on the device that runs
+    it."""
     for m, pool in enumerate(pools):
-        engine.submit(m, pool[0])
+        seen = set()
+        for x in pool:
+            kind = (np.shape(x), np.result_type(x))
+            if kind not in seen:
+                seen.add(kind)
+                engine.submit(m, x)
     bad = [c for c in engine.drain(timeout) if not c.ok]
     if bad:
         raise RuntimeError(f"warm-up request failed: {bad[0].error!r}")
@@ -292,6 +259,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     """One run; returns the result line as a dict."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell = load_cell(root, workload)
+    fam = family(root, cell.config)
+    if control is not None and control not in fam.CONTROLS:
+        raise ValueError(f"{family_path(root, cell.config).stem} has no "
+                         f"control {control!r}; it has {fam.CONTROLS}")
     import jax
 
     devices = jax.devices()
@@ -322,16 +293,16 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             f"{clock.cache_hits} cache hits)")
 
     phase("start")
-    params, pools = make_data(cfg, int(mix["pool_size"]), seed, dev)
+    params, pools = fam.make_data(cfg, int(mix["pool_size"]), seed, dev)
     jax.block_until_ready((params, pools))
     phase("weights and inputs made")
-    host_pools = [np.asarray(p) for p in pools]
+    host_pools = jax.device_get(pools)
     del pools
     phase("inputs on the host")
-    plan = make_plan(cfg, rates)
+    plan = fam.make_plan(cfg, rates)
     log(f"plan: partition={plan.partition} cores={plan.cores} at "
         f"{[round(float(r), 3) for r in rates]} req/s")
-    engine = ServingEngine(build_models(cfg, params, control), plan,
+    engine = ServingEngine(fam.build_models(cfg, params, control), plan,
                            k_max=int(cfg["k_max"]))
     drain_s = float(mix["drain_timeout_s"])
     phase("engine built")
@@ -394,7 +365,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     run = Run(plan=plan, seconds=seconds, setup_s=setup_s,
               tenant=sched.tenant, due=sched.due, submit=submit_t, done=done,
-              costs=[roofline.stage_costs(cfg, t) for t in cfg["tenants"]],
+              costs=[fam.stage_costs(cfg, t) for t in cfg["tenants"]],
               peak=peak, traced_s=traced_s)
     if trace:
         run.events = tracing.load(trace_dir)
@@ -407,7 +378,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     # The check, after the window and with the program's state freed.
     t_check = time.perf_counter()
-    refs = reference.references(cfg, host_params, host_pools)
+    refs = fam.references(cfg, host_params, host_pools)
     errs = [reference.relative_error(o, refs[sched.tenant[k]][sched.item[k]])
             for k, o in enumerate(outputs) if o is not None]
     limit = float(cfg["check"]["max_rel_err"])

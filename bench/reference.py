@@ -90,8 +90,12 @@ def references(config: dict, params: list[list], pools: list[np.ndarray],
 
 def relative_error(y, ref: np.ndarray) -> float:
     """``max|y - ref| / max|ref|``; infinite when the shapes differ or
-    ``y`` is not finite."""
+    ``y`` is not finite.  Against an all-zero reference it is 0 where ``y``
+    is all zero too, and infinite otherwise."""
     y = np.asarray(y, dtype=np.float32)
     if y.shape != ref.shape or not np.all(np.isfinite(y)):
         return float("inf")
-    return float(np.abs(y - ref).max() / np.abs(ref).max())
+    diff, scale = np.abs(y - ref).max(), np.abs(ref).max()
+    if scale == 0:
+        return 0.0 if diff == 0 else float("inf")
+    return float(diff / scale)

@@ -95,7 +95,7 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--sets", type=int, default=1)
     ap.add_argument("--rates", default=None)
-    ap.add_argument("--control", choices=("bfloat16", "fp8"), default=None)
+    ap.add_argument("--control", default=None)
     ap.add_argument("--timeout", type=float, default=1200)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()

@@ -35,11 +35,13 @@ TINY_MIX = {"name": "tiny_mix", "arrivals": "poisson", "rate_rps": 40.0,
 def write_tree(root: Path, config=TINY_CONFIG, mix=TINY_MIX,
                workload="tiny.load") -> Path:
     """A benchmark tree under ``root`` holding one cell, with the
-    repository's metric readers."""
+    repository's metric readers and model families."""
     (root / "bench" / "configs").mkdir(parents=True, exist_ok=True)
     (root / "bench" / "mixes").mkdir(parents=True, exist_ok=True)
-    shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics",
-                    dirs_exist_ok=True)
+    for d in ("metrics", "families"):
+        shutil.copytree(REPO / "bench" / d, root / "bench" / d,
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs" / f"{config['name']}.json").write_text(
         json.dumps(config))
     (root / "bench" / "mixes" / f"{mix['name']}.json").write_text(json.dumps(mix))

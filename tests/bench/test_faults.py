@@ -5,6 +5,8 @@ control in the program's place."""
 from bench import harness
 from repro.serving.engine import ServingEngine
 
+from .conftest import TINY_CONFIG
+
 
 def run(root, **kw):
     return harness.run_cell(root, "tiny.load", 31, 1.0, False,
@@ -22,7 +24,8 @@ def test_the_fp8_control_is_not_correct(tiny_root):
 
 
 def test_a_stage_that_returns_its_state_unchanged(tiny_root, monkeypatch):
-    build = harness.build_models
+    conv_chain = harness.family(tiny_root, TINY_CONFIG)
+    build = conv_chain.build_models
 
     def broken(config, params, control):
         models = build(config, params, control)
@@ -32,7 +35,7 @@ def test_a_stage_that_returns_its_state_unchanged(tiny_root, monkeypatch):
         models[1] = type(m)(m.name, tuple(segs), m.params, m.make_input)
         return models
 
-    monkeypatch.setattr(harness, "build_models", broken)
+    monkeypatch.setattr(conv_chain, "build_models", broken)
     assert not run(tiny_root)["correct"]
 
 

@@ -66,6 +66,86 @@ def test_a_new_cell_mix_and_metric_need_only_new_files(tiny_root):
     assert after == before
 
 
+TOY_CONFIG = {
+    "name": "toy", "family": "toy_dense", "source": "test", "k_max": 4,
+    "tenants": [
+        {"name": "dense_a", "widths": [8, 16, 16, 8], "lengths": [4, 6],
+         "partition": 1, "cores": 1},
+        {"name": "dense_b", "widths": [8, 12, 8], "lengths": [3, 5],
+         "partition": 1, "cores": 2},
+    ],
+    "check": {"max_rel_err": 1e-4},
+}
+
+
+def add_toy_family(root):
+    """Add a family, a configuration of it, a mix and a cell by new files
+    and new entries; the bench files that were there before, by path."""
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*") if p.is_file()}
+    shutil.copy(REPO / "tests/bench/toy_dense.py",
+                root / "bench/families/toy_dense.py")
+    (root / "bench/configs/toy.json").write_text(json.dumps(TOY_CONFIG))
+    (root / "bench/mixes/toy_mix.json").write_text(
+        json.dumps(dict(TINY_MIX, name="toy_mix", rate_rps=60.0)))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "toy", "source": "test",
+                            "file": "bench/configs/toy.json", "reduced": [],
+                            "why": "test"})
+    spec["workloads"].append({"name": "toy.load", "config": "toy",
+                              "traffic": "toy_mix", "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        m["workloads"].append("toy.load")
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return before
+
+
+def test_a_new_family_needs_only_new_files(tiny_root):
+    before = add_toy_family(tiny_root)
+    r = harness.run_cell(tiny_root, "toy.load", 2**33 + 9, 1.0, False,
+                         require_tpu=False, log=lambda m: None)
+    assert r["correct"], r["check"]
+    assert r["attempted"] == 60 and r["failed"] == 0
+    assert r["check"]["max_rel_err"]["value"] < 1e-5
+    # Warm-up sent each tenant's two input lengths: nothing compiles later.
+    assert r["window"]["compiles_in_window"] == 0
+    assert r["window"]["plan"]["partition"] == [1, 1]
+    assert set(r["metrics"]) == {"p50_ms", "p99_ms", "setup_s"}
+
+    r = harness.run_cell(tiny_root, "toy.load", 6, 1.0, True,
+                         require_tpu=False, log=lambda m: None)
+    assert r["correct"], r["check"]
+    # Both tenants are cut after their first segment, at a mean length of 5
+    # and 4 tokens and a width of 16 and 12 float32 values.
+    cut = r["metrics"]["cut_kb_per_req"]["value"]
+    assert 4 * 4 * 12 / 1e3 < cut < 4 * 5 * 16 / 1e3
+    assert "gen_late_p99_ms" in r["metrics"]
+    # No device plane on the CPU: the device's metrics have nothing to read.
+    assert "device_idle_frac" not in r["metrics"]
+    assert {p: p.read_bytes() for p in before} == before
+
+
+def test_a_new_familys_control_is_not_correct(tiny_root):
+    add_toy_family(tiny_root)
+    r = harness.run_cell(tiny_root, "toy.load", 12, 1.0, False,
+                         require_tpu=False, control="bfloat16", log=lambda m: None)
+    assert not r["correct"]
+    assert r["check"]["max_rel_err"]["value"] > r["check"]["max_rel_err"]["limit"]
+
+
+def test_a_control_the_family_does_not_list_is_refused(tiny_root):
+    with pytest.raises(ValueError, match="no control"):
+        harness.run_cell(tiny_root, "tiny.load", 1, 1.0, False,
+                         require_tpu=False, control="int4", log=lambda m: None)
+
+
+def test_an_unknown_family_is_an_error(tiny_root):
+    path = tiny_root / "bench/configs/tiny.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    family="no_such_family")))
+    with pytest.raises(FileNotFoundError, match="no_such_family"):
+        harness.load_cell(tiny_root, "tiny.load")
+
+
 def test_a_cpu_run_drives_the_path_and_checks_every_output(tiny_root):
     r = harness.run_cell(tiny_root, "tiny.load", 2**33 + 1, 1.0, False,
                          require_tpu=False, log=lambda m: None)

@@ -6,12 +6,11 @@ import jax
 import numpy as np
 import pytest
 
-from bench import reference
-from bench.harness import make_data
+from bench import harness, reference
 from repro.models.cnn import CNNSpec, build_executable
 from repro.models.cnn import reference as program_reference
 
-from .conftest import TINY_CONFIG
+from .conftest import REPO, TINY_CONFIG
 
 LIMIT = TINY_CONFIG["check"]["max_rel_err"]
 
@@ -21,7 +20,8 @@ def tenant():
     """The mnasnet stand-in of the tiny configuration, weights and inputs
     from the benchmark's seed, on the CPU."""
     t = TINY_CONFIG["tenants"][1]
-    params, pools = make_data(TINY_CONFIG, 4, 123, jax.devices("cpu")[0])
+    conv_chain = harness.family(REPO, TINY_CONFIG)
+    params, pools = conv_chain.make_data(TINY_CONFIG, 4, 123, jax.devices("cpu")[0])
     params, pool = jax.device_get(params[1]), np.asarray(pools[1])
     spec = CNNSpec(t["name"], tuple(t["stage_channels"]), in_size=t["input_size"])
     model = dataclasses.replace(build_executable(spec), params=tuple(params))
@@ -83,3 +83,16 @@ def test_relative_error_refuses_wrong_shape_and_nan():
     assert reference.relative_error(np.ones((1, 2, 2, 4)), ref) == float("inf")
     assert reference.relative_error(np.full_like(ref, np.nan), ref) == float("inf")
     assert reference.relative_error(ref * 1.01, ref) == pytest.approx(0.01, rel=1e-3)
+
+
+def test_relative_error_against_an_all_zero_reference():
+    zero = np.zeros((1, 2, 2, 3), np.float32)
+    assert reference.relative_error(zero, zero) == 0.0
+    y = zero.copy()
+    y[0, 1, 0, 2] = 1e-30
+    assert reference.relative_error(y, zero) == float("inf")
+    assert reference.relative_error(-y, zero) == float("inf")
+    # A reference with one non-zero entry keeps the plain ratio.
+    ref = zero.copy()
+    ref[0, 0, 0, 0] = 2.0
+    assert reference.relative_error(zero, ref) == 1.0
